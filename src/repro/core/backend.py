@@ -86,6 +86,7 @@ class BackendStats:
     # report the fp32 join share). ``bin_points`` maps each size-class edge to
     # cumulative (valid, padded) point totals packed under it.
     prune_tier_dispatches: int = 0         # coarse counts passes issued
+    join_dispatches: int = 0               # fp32 masked-join passes issued
     cells_pruned: int = 0                  # fp32 tile cells skipped via prune
     t_prune_s: float = 0.0                 # wall inside coarse counts passes
     host_routed_dispatches: int = 0        # bins routed to the host backend
@@ -1060,6 +1061,7 @@ class PallasBackend(DistanceBackend):
             mask = np.asarray(m)
             counts = np.asarray(c)
             dt = time.perf_counter() - t1
+            self.stats.join_dispatches += 1
             self.stats.t_dispatch_s += dt
             self.stats.d2h_bytes += mask.nbytes + counts.nbytes
             if sharded:

@@ -45,7 +45,6 @@ import dataclasses
 
 import jax
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -195,10 +194,10 @@ class DevicePlane:
                         impl=impl, interpret=interpret)
 
             n_in = 4 if with_elig else 3
-            sharded = shard_map(body, mesh=self.mesh,
-                                in_specs=(P(ax),) * n_in,
-                                out_specs=(P(ax), P(ax)),
-                                check_rep=False)
+            sharded = jax.shard_map(body, mesh=self.mesh,
+                                    in_specs=(P(ax),) * n_in,
+                                    out_specs=(P(ax), P(ax)),
+                                    check_vma=False)
             fn = jax.jit(sharded,
                          in_shardings=(self.sharding(P(ax)),) * n_in)
             self._join_fns[key] = fn
@@ -248,10 +247,10 @@ class DevicePlane:
                         impl=impl, interpret=interpret)
 
             n_in = 4 if with_elig else 3
-            sharded = shard_map(body, mesh=self.mesh,
-                                in_specs=(P(ax),) * n_in,
-                                out_specs=P(ax),
-                                check_rep=False)
+            sharded = jax.shard_map(body, mesh=self.mesh,
+                                    in_specs=(P(ax),) * n_in,
+                                    out_specs=P(ax),
+                                    check_vma=False)
             fn = jax.jit(sharded,
                          in_shardings=(self.sharding(P(ax)),) * n_in)
             self._join_fns[key] = fn
@@ -320,11 +319,11 @@ class DevicePlane:
                 return replicated_topk_merge(ax, diams, cids, k)
 
             spec_in = P(None, self.axis, None)
-            fn = jax.jit(shard_map(body, mesh=self.mesh,
-                                   in_specs=(spec_in, P(None, self.axis),
-                                             P(None, self.axis)),
-                                   out_specs=(P(), P()),
-                                   check_rep=False))
+            fn = jax.jit(jax.shard_map(body, mesh=self.mesh,
+                                       in_specs=(spec_in, P(None, self.axis),
+                                                 P(None, self.axis)),
+                                       out_specs=(P(), P()),
+                                       check_vma=False))
             self._nks_fns[k] = fn
         return fn
 

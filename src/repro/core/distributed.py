@@ -28,27 +28,31 @@ plane rounds R up to shard multiples); re-exported here unchanged.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
 from repro.core.device_plane import (DevicePlane, PackedGroups,  # noqa: F401
                                      pack_groups)
 
-BIG = jnp.float32(3.4e38)
+BIG = np.float32(3.4e38)     # host constant: importing starts no backend
 
 
 def _masked_sq_dists(a, b, b_mask):
     """(A,d) x (B,d) -> (A,B) squared L2 with invalid b masked to +BIG."""
     a = a.astype(jnp.float32)
     b = b.astype(jnp.float32)
-    sq = (jnp.sum(a * a, 1)[:, None] + jnp.sum(b * b, 1)[None, :]
-          - 2.0 * (a @ b.T))
+    ab = jnp.matmul(a, b.T, precision=jax.lax.Precision.HIGHEST)
+    sq = jnp.sum(a * a, 1)[:, None] + jnp.sum(b * b, 1)[None, :] - 2.0 * ab
     sq = jnp.maximum(sq, 0.0)
     return jnp.where(b_mask[None, :], sq, BIG)
 
 
+@functools.partial(jax.jit, static_argnames=("k",))
 def nks_anchor_topk(groups, mask, ids, k: int, *, anchors=None,
                     anchor_mask=None, anchor_ids=None):
     """Anchor-star NKS top-k on one shard.
@@ -59,7 +63,9 @@ def nks_anchor_topk(groups, mask, ids, k: int, *, anchors=None,
     Points are centred before the distance math: the fp32
     ||a||^2+||b||^2-2ab identity cancels catastrophically for large
     coordinates (same contract as the Pallas join kernel — this tier is a
-    fast filter; exact rescoring runs in float64 on the control plane).
+    fast filter; the engine rescores the k sets it returns in float64).
+    Compiled as one program (op-by-op dispatch would compile every
+    primitive per shape); the plane calls it inside its shard_map body.
     """
     q = groups.shape[0]
     center = jnp.sum(jnp.where(mask[..., None], groups, 0.0), axis=(0, 1)) \
@@ -88,7 +94,8 @@ def nks_anchor_topk(groups, mask, ids, k: int, *, anchors=None,
     # exact diameter of each candidate (the paper's r(A) ranking)
     pts = tuples.astype(jnp.float32)
     sq = jnp.sum(pts * pts, -1)
-    gram = jnp.einsum("aqd,ard->aqr", pts, pts)
+    gram = jnp.einsum("aqd,ard->aqr", pts, pts,
+                      precision=jax.lax.Precision.HIGHEST)
     d2 = jnp.maximum(sq[:, :, None] + sq[:, None, :] - 2.0 * gram, 0.0)
     diam = jnp.sqrt(jnp.max(d2, axis=(1, 2)))
 

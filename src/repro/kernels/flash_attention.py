@@ -40,8 +40,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, bq: int, bk: int, t: int,
 
     def body(i, carry):
         m_run, l_run, acc = carry
-        k_c = pl.load(k_ref, (pl.dslice(i * bk, bk), slice(None)))
-        v_c = pl.load(v_ref, (pl.dslice(i * bk, bk), slice(None)))
+        k_c = k_ref[pl.ds(i * bk, bk), :]
+        v_c = v_ref[pl.ds(i * bk, bk), :]
         sc = jax.lax.dot_general(
             q.astype(k_c.dtype), k_c, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)          # (bq, bk)

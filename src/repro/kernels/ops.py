@@ -1,8 +1,10 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On CPU (this container) the kernels execute with ``interpret=True`` — the
-kernel body runs in Python per grid step, which validates the exact TPU
-program logic. On a TPU backend the same wrappers emit Mosaic kernels.
+On a TPU backend the wrappers emit Mosaic kernels (``interpret=False``);
+``tests/test_tpu_compile.py`` compiles them for a described v5e and
+``chip_smoke.py`` runs them on a chip. Off-TPU, ``interpret=None`` resolves
+to ``interpret=True``: the kernel body runs in Python per grid step, which
+checks the program logic but says nothing about what Mosaic accepts.
 
 The serving hot path (:func:`pairwise_l2_join_batched_masked`) additionally
 routes by *implementation*: the Pallas program is a Mosaic artifact, and

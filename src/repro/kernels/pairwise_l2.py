@@ -27,10 +27,10 @@ to) the dense fp32 block: word ``mask[s, i, w]`` holds bits for columns
 ``sq[s, i, j] <= r[s]^2`` and both endpoints are valid. The mask is the
 enumeration stage's entire join contract, so the D2H readback shrinks 32x
 (uint32 words vs fp32 cells) and the dense ``sq`` block becomes optional.
-In-kernel packing rides the MXU: the 0/1 bit tile is multiplied by a static
-(bn, 2W) weight matrix of powers of two that accumulates each 16-bit half-word
-exactly in fp32 (max 0xFFFF < 2^24), and the halves are fused into uint32
-words with one shift-or.
+In-kernel packing rides the MXU: a static (2W, bn) weight matrix of powers of
+two times the 0/1 bit tile accumulates each 16-bit half-word exactly in fp32
+(max 0xFFFF < 2^24), and the halves are fused into 32-bit words with one
+shift-or.
 
 Grid is (ceil(M/bm), ceil(N/bn)) (with a leading subset axis for the batched
 variant); the full d extent is kept per block (for the embedding widths we
@@ -38,11 +38,22 @@ index, bm*d*4B + bn*d*4B + bm*bn*4B stays well inside the ~16 MiB v5e VMEM
 budget: 128x8192 fp32 tiles are 4 MiB each). Tail tiles are masked with an
 in-kernel iota validity test — no host-side padding games.
 
+Mosaic layout rules (checked by AOT compiles for a described v5e in
+``tests/test_tpu_compile.py``): the last two dims of every block are
+(8, 128)-divisible or span the whole array dim, and nothing stores a scalar
+to VMEM. So each tile writes its join counts as a lane-dense (1, bn) row of
+per-column counts (the wrapper sums the rows back into the per-tile
+(gm, gn) grid), the mask words leave the kernel word-major as (W, bm) tiles
+of an (S, gn, W, P) array (the wrapper transposes them into the
+(S, P, ceil(P/32)) contract), and eligibility rides as (1, P) rows that
+enter the counts through a (1, bm) x (bm, bn) matvec. Words are built in
+int32 (Mosaic has no uint32<->f32 casts) and bitcast to uint32 outside the
+kernel. The fp32 Gram matmul asks for ``Precision.HIGHEST``: the slack
+contract of ``core.backend`` assumes fp32 rounding, not a single bf16 pass.
+
 MXU notes: bm=bn=128 aligns the matmul to the 128x128 systolic array;
 ``preferred_element_type=float32`` keeps the accumulator fp32 even for bf16
-inputs. The masked variant is interpret-validated; its (bm, bn//32) output
-tile is narrower than one lane register, which Mosaic pads — real-TPU lane
-utilisation of the mask store is part of the ROADMAP v5e validation item.
+inputs.
 """
 from __future__ import annotations
 
@@ -61,8 +72,23 @@ def _join_block(a, b):
     a2 = jnp.sum(a * a, axis=1, keepdims=True)    # (bm, 1)
     b2 = jnp.sum(b * b, axis=1, keepdims=True)    # (bn, 1)
     ab = jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                             precision=jax.lax.Precision.HIGHEST,
                              preferred_element_type=jnp.float32)  # (bm, bn)
     return jnp.maximum(a2 + b2.T - 2.0 * ab, 0.0)
+
+
+def _col_counts(joined):
+    """(bm, bn) bool -> (1, bn) int32 per-column join counts (exact: the
+    fp32 column sums stay <= bm < 2^24)."""
+    return jnp.sum(joined.astype(jnp.float32), axis=0,
+                   keepdims=True).astype(jnp.int32)
+
+
+def _tile_counts(rows, gm: int, gn: int, bn: int):
+    """(..., gm, 1, gn*bn) per-column count rows -> (..., gm, gn) per-tile
+    join sizes."""
+    lead = rows.shape[:-3]
+    return rows.reshape(*lead, gm, gn, bn).sum(axis=-1, dtype=jnp.int32)
 
 
 def _kernel(r2_ref, a_ref, b_ref, sq_ref, cnt_ref, *, m_actual: int,
@@ -76,7 +102,7 @@ def _kernel(r2_ref, a_ref, b_ref, sq_ref, cnt_ref, *, m_actual: int,
     valid = rows & cols
     sq = jnp.where(valid, sq, jnp.float32(_FMAX))
     sq_ref[...] = sq
-    cnt_ref[0, 0] = jnp.sum((sq <= r2_ref[0]) & valid, dtype=jnp.int32)
+    cnt_ref[0] = _col_counts((sq <= r2_ref[0]) & valid)
 
 
 def pairwise_l2_join(a: jax.Array, b: jax.Array,
@@ -105,7 +131,7 @@ def pairwise_l2_join(a: jax.Array, b: jax.Array,
         ],
         out_specs=[
             pl.BlockSpec((bm, bn), lambda i, j, r2_ref: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j, r2_ref: (i, j)),
+            pl.BlockSpec((1, 1, bn), lambda i, j, r2_ref: (i, 0, j)),
         ],
     )
     sq, cnt = pl.pallas_call(
@@ -113,11 +139,11 @@ def pairwise_l2_join(a: jax.Array, b: jax.Array,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((gm * bm, gn * bn), jnp.float32),
-            jax.ShapeDtypeStruct((gm, gn), jnp.int32),
+            jax.ShapeDtypeStruct((gm, 1, gn * bn), jnp.int32),
         ],
         interpret=interpret,
     )(r2, a_p, b_p)
-    return sq[:m, :n], cnt
+    return sq[:m, :n], _tile_counts(cnt, gm, gn, bn)
 
 
 def _batched_kernel(len_ref, r2_ref, a_ref, b_ref, sq_ref, cnt_ref, *,
@@ -133,7 +159,12 @@ def _batched_kernel(len_ref, r2_ref, a_ref, b_ref, sq_ref, cnt_ref, *,
     valid = rows & cols
     sq = jnp.where(valid, sq, jnp.float32(_FMAX))
     sq_ref[0] = sq
-    cnt_ref[0, 0, 0] = jnp.sum((sq <= r2_ref[s]) & valid, dtype=jnp.int32)
+    cnt_ref[0, 0] = _col_counts((sq <= r2_ref[s]) & valid)
+
+
+def _count_spec(bn: int):
+    """(1, bn) count row of tile (s, i, j) in an (S, gm, 1, gn*bn) array."""
+    return pl.BlockSpec((1, 1, 1, bn), lambda s, i, j, *_: (s, i, 0, j))
 
 
 def pairwise_l2_join_batched(x: jax.Array, lengths: jax.Array,
@@ -169,7 +200,7 @@ def pairwise_l2_join_batched(x: jax.Array, lengths: jax.Array,
         ],
         out_specs=[
             pl.BlockSpec((1, bm, bn), lambda s, i, j, *_: (s, i, j)),
-            pl.BlockSpec((1, 1, 1), lambda s, i, j, *_: (s, i, j)),
+            _count_spec(bn),
         ],
     )
     sq, cnt = pl.pallas_call(
@@ -177,11 +208,11 @@ def pairwise_l2_join_batched(x: jax.Array, lengths: jax.Array,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((n_subsets, gm * bm, gn * bn), jnp.float32),
-            jax.ShapeDtypeStruct((n_subsets, gm, gn), jnp.int32),
+            jax.ShapeDtypeStruct((n_subsets, gm, 1, gn * bn), jnp.int32),
         ],
         interpret=interpret,
     )(lengths, r2, x_p, x_p)
-    return sq[:, :p, :p], cnt
+    return sq[:, :p, :p], _tile_counts(cnt, gm, gn, bn)
 
 
 def _prune_block(a, b):
@@ -211,8 +242,13 @@ def _batched_prune_kernel(len_ref, r2_ref, a_ref, b_ref, ea_ref, eb_ref,
     n_valid = len_ref[s]
     rows = (i * bm + jax.lax.broadcasted_iota(jnp.int32, (bm, bn), 0)) < n_valid
     cols = (j * bn + jax.lax.broadcasted_iota(jnp.int32, (bm, bn), 1)) < n_valid
-    valid = rows & cols & (ea_ref[0][:, None] > 0.0) & (eb_ref[0][None, :] > 0.0)
-    cnt_ref[0, 0, 0] = jnp.sum((sq <= r2_ref[s]) & valid, dtype=jnp.int32)
+    joined = ((sq <= r2_ref[s]) & rows & cols).astype(jnp.float32)
+    # Row eligibility enters as a (1, bm) x (bm, bn) matvec (0/1 operands,
+    # exact), column eligibility as a lane-wise product: both stay rows, so
+    # no (bm, 1) column has to be laid out.
+    per_col = jax.lax.dot_general(ea_ref[0], joined, (((1,), (0,)), ((), ())),
+                                  preferred_element_type=jnp.float32)
+    cnt_ref[0, 0] = (per_col * eb_ref[0]).astype(jnp.int32)
 
 
 def pairwise_l2_join_batched_prune(x: jax.Array, lengths: jax.Array,
@@ -241,7 +277,8 @@ def pairwise_l2_join_batched_prune(x: jax.Array, lengths: jax.Array,
     gn = pl.cdiv(p, bn)
     p_pad = max(gm * bm, gn * bn)
     x_p = jnp.pad(x.astype(jnp.bfloat16), ((0, 0), (0, p_pad - p), (0, 0)))
-    e_p = jnp.pad(jnp.asarray(elig, jnp.float32), ((0, 0), (0, p_pad - p)))
+    e_p = jnp.pad(jnp.asarray(elig, jnp.float32),
+                  ((0, 0), (0, p_pad - p)))[:, None, :]       # (S, 1, P)
     lengths = jnp.asarray(lengths, jnp.int32).reshape((n_subsets,))
     r2 = jnp.square(jnp.broadcast_to(jnp.asarray(r, jnp.float32), (n_subsets,)))
 
@@ -252,42 +289,42 @@ def pairwise_l2_join_batched_prune(x: jax.Array, lengths: jax.Array,
         in_specs=[
             pl.BlockSpec((1, bm, d), lambda s, i, j, *_: (s, i, 0)),
             pl.BlockSpec((1, bn, d), lambda s, i, j, *_: (s, j, 0)),
-            pl.BlockSpec((1, bm), lambda s, i, j, *_: (s, i)),
-            pl.BlockSpec((1, bn), lambda s, i, j, *_: (s, j)),
+            pl.BlockSpec((1, 1, bm), lambda s, i, j, *_: (s, 0, i)),
+            pl.BlockSpec((1, 1, bn), lambda s, i, j, *_: (s, 0, j)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1, 1), lambda s, i, j, *_: (s, i, j)),
-        ],
+        out_specs=[_count_spec(bn)],
     )
     (cnt,) = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((n_subsets, gm, gn), jnp.int32)],
+        out_shape=[jax.ShapeDtypeStruct((n_subsets, gm, 1, gn * bn),
+                                        jnp.int32)],
         interpret=interpret,
     )(lengths, r2, x_p, x_p, e_p, e_p)
-    return cnt
+    return _tile_counts(cnt, gm, gn, bn)
 
 
 def _pack_bits_mxu(bits: jax.Array, bn: int) -> jax.Array:
-    """(bm, bn) 0/1 fp32 -> (bm, bn//32) uint32 words, LSB-first per word.
+    """(bm, bn) 0/1 fp32 -> (bn//32, bm) int32 words, word-major: entry
+    ``[w, i]`` holds columns 32w..32w+31 of row i, LSB-first.
 
-    One MXU matmul against a static (bn, 2W) powers-of-two weight accumulates
-    the low/high 16-bit halves of every word exactly in fp32 (<= 0xFFFF), then
-    a shift-or fuses them. Avoids >=3D reshapes inside the kernel, which keeps
-    the Mosaic lowering to plain 2D vector/matrix ops.
+    One MXU matmul of a static (2W, bn) powers-of-two weight against the bit
+    tile accumulates the low/high 16-bit halves of every word exactly in fp32
+    (<= 0xFFFF; the operands are exact in bf16, so no precision setting can
+    round them), then a shift-or fuses them. The word-major result is a
+    lane-dense (W, bm) tile, and no >=3D reshape enters the kernel.
     """
     w = bn // 32
-    cc = jax.lax.broadcasted_iota(jnp.int32, (bn, 2 * w), 0)     # column id
-    hh = jax.lax.broadcasted_iota(jnp.int32, (bn, 2 * w), 1)     # half slot
+    hh = jax.lax.broadcasted_iota(jnp.int32, (2 * w, bn), 0)     # half slot
+    cc = jax.lax.broadcasted_iota(jnp.int32, (2 * w, bn), 1)     # column id
     target = cc // 32 + w * ((cc // 16) % 2)   # lo halves 0..W-1, hi W..2W-1
     # powers of two via integer shift: jnp.exp2 is a polynomial approximation
     # in fp32 (2^13 -> 8192.0039) and would corrupt the packed words
-    pow2 = (jnp.uint32(1) << (cc % 16).astype(jnp.uint32)).astype(jnp.float32)
+    pow2 = (jnp.int32(1) << (cc % 16)).astype(jnp.float32)
     weight = jnp.where(hh == target, pow2, 0.0)
-    halves = jax.lax.dot_general(bits, weight, (((1,), (0,)), ((), ())),
+    halves = jax.lax.dot_general(weight, bits, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-    return (halves[:, :w].astype(jnp.uint32)
-            | (halves[:, w:].astype(jnp.uint32) << 16))
+    return halves[:w].astype(jnp.int32) | (halves[w:].astype(jnp.int32) << 16)
 
 
 def _batched_masked_kernel(len_ref, r2_ref, a_ref, b_ref, *out_refs,
@@ -308,8 +345,8 @@ def _batched_masked_kernel(len_ref, r2_ref, a_ref, b_ref, *out_refs,
         sq_ref[0] = sq
     else:
         mask_ref, cnt_ref = out_refs
-    mask_ref[0] = _pack_bits_mxu(joined.astype(jnp.float32), bn)
-    cnt_ref[0, 0, 0] = jnp.sum(joined, dtype=jnp.int32)
+    mask_ref[0, 0] = _pack_bits_mxu(joined.astype(jnp.float32), bn)
+    cnt_ref[0, 0] = _col_counts(joined)
 
 
 def pairwise_l2_join_batched_masked(x: jax.Array, lengths: jax.Array,
@@ -344,12 +381,12 @@ def pairwise_l2_join_batched_masked(x: jax.Array, lengths: jax.Array,
     kern = functools.partial(_batched_masked_kernel, bm=bm, bn=bn,
                              with_sq=with_sq)
     out_specs = [
-        pl.BlockSpec((1, bm, wn), lambda s, i, j, *_: (s, i, j)),
-        pl.BlockSpec((1, 1, 1), lambda s, i, j, *_: (s, i, j)),
+        pl.BlockSpec((1, 1, wn, bm), lambda s, i, j, *_: (s, j, 0, i)),
+        _count_spec(bn),
     ]
     out_shape = [
-        jax.ShapeDtypeStruct((n_subsets, gm * bm, gn * wn), jnp.uint32),
-        jax.ShapeDtypeStruct((n_subsets, gm, gn), jnp.int32),
+        jax.ShapeDtypeStruct((n_subsets, gn, wn, p_pad), jnp.int32),
+        jax.ShapeDtypeStruct((n_subsets, gm, 1, gn * bn), jnp.int32),
     ]
     if with_sq:
         out_specs.insert(0, pl.BlockSpec((1, bm, bn),
@@ -367,9 +404,16 @@ def pairwise_l2_join_batched_masked(x: jax.Array, lengths: jax.Array,
     )
     out = pl.pallas_call(kern, grid_spec=grid_spec, out_shape=out_shape,
                          interpret=interpret)(lengths, r2, x_p, x_p)
-    n_words = (p + 31) // 32
     if with_sq:
-        sq, mask, cnt = out
-        return mask[:, :p, :n_words], cnt, sq[:, :p, :p]
-    mask, cnt = out
-    return mask[:, :p, :n_words], cnt
+        sq, words, cnt = out
+    else:
+        words, cnt = out
+    # (S, gn, W, P) word-major tiles -> (S, P, gn*W) row-major words
+    mask = jax.lax.bitcast_convert_type(words, jnp.uint32) \
+        .transpose(0, 3, 1, 2).reshape(n_subsets, p_pad, gn * wn)
+    n_words = (p + 31) // 32
+    mask = mask[:, :p, :n_words]
+    cnt = _tile_counts(cnt, gm, gn, bn)
+    if with_sq:
+        return mask, cnt, sq[:, :p, :p]
+    return mask, cnt

@@ -40,7 +40,8 @@ multi-tenant corpus).
 
 ``--runtime`` routes requests through the fault-tolerant async runtime
 (``serve.runtime``): consecutive queries are admitted together and coalesced
-into batched dispatches; ingest ops are awaited before later requests are
+into batched dispatches (``--backend pallas`` runs their joins on the
+accelerator); ingest ops are awaited before later requests are
 admitted, preserving the stream contract. Responses gain ``degraded: true``
 when overload shed an exact request to the approx tier. ``--wal DIR``
 attaches the crash-recovery write-ahead log — every ingest ack is then
@@ -62,6 +63,7 @@ import numpy as np
 from repro.core.semantics import parse_weighted_keywords
 from repro.data.flickr_like import flickr_like_dataset
 from repro.data.synthetic import random_queries, synthetic_dataset
+from repro.launch.compile_cache import use_compile_cache
 from repro.serve.engine import NKSEngine
 
 
@@ -335,6 +337,12 @@ def main():
                     help="serve through the async fault-tolerant runtime "
                          "(admission queue, coalesced batches, off-thread "
                          "compaction)")
+    ap.add_argument("--backend", choices=["numpy", "pallas"],
+                    default="numpy",
+                    help="distance backend for the runtime's exact/approx "
+                         "batches (with --runtime): pallas runs the join "
+                         "kernels on the accelerator (Mosaic on a TPU, the "
+                         "XLA lowering elsewhere)")
     ap.add_argument("--max-queue", type=int, default=256,
                     help="runtime admission-queue bound (backpressure past "
                          "it)")
@@ -361,6 +369,9 @@ def main():
                     help="persist the ingestion job journal here (reopening "
                          "resumes unfinished jobs); default: a temp dir")
     args = ap.parse_args()
+    if args.backend != "numpy" and not args.runtime:
+        ap.error("--backend applies to the --runtime path")
+    use_compile_cache()
 
     if args.tenants:
         from repro.data.synthetic import synthetic_tenants
@@ -383,7 +394,8 @@ def main():
     print(f"serving: corpus N={ds.n} d={ds.dim} U={ds.n_keywords} "
           f"tier={args.tier}"
           + (f" wal={args.wal}" if args.wal else "")
-          + (" runtime=async" if args.runtime else ""), file=sys.stderr)
+          + (f" runtime=async backend={args.backend}" if args.runtime
+             else ""), file=sys.stderr)
 
     if args.requests:
         reqs = []
@@ -404,7 +416,7 @@ def main():
             max_queue=args.max_queue, max_batch=args.max_batch,
             batch_window_s=args.batch_window_ms / 1e3,
             default_deadline_s=args.deadline_s,
-            tier=args.tier, k=args.k))
+            tier=args.tier, k=args.k, backend=args.backend))
         try:
             if args.ingest_docs:
                 _run_ingest_pipeline(runtime, ds, args)

@@ -161,7 +161,6 @@ def _try_flash(q, k, v, g: int, *, causal: bool, window: int | None):
     mesh = (ctx or {}).get("mesh")
     if mesh is None:
         return fn(q, kf, vf)
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     dp, tp = ctx["dp"], ctx["tp"]
     dp_n = hints_mod._axis_size(dp)
@@ -169,8 +168,8 @@ def _try_flash(q, k, v, g: int, *, causal: bool, window: int | None):
     if b % dp_n or h % tp_n:
         return None
     spec = P(dp, None, tp, None)
-    sm = shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                   out_specs=spec, check_rep=False)
+    sm = jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                       out_specs=spec, check_vma=False)
     return sm(q, kf, vf)
 
 

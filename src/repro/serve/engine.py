@@ -58,7 +58,7 @@ from typing import Sequence
 
 import numpy as np
 
-from repro.core import plan, promish_a, promish_e
+from repro.core import brute_force, plan, promish_a, promish_e
 from repro.core import store as storemod
 from repro.core.backend import DistanceBackend, get_backend
 from repro.core.filters import Filter
@@ -900,7 +900,10 @@ class NKSEngine:
         single-device kernel — the device tier's unit of work. ``eligible``
         (a filtered query's point mask) restricts the packed groups; a group
         the filter empties means no feasible candidate, so the dispatch is
-        skipped outright."""
+        skipped outright. The device's fp32 diameters only select the k
+        sets; each is rescored in float64 on the host and the k are ranked
+        by that, so every mesh that selects the same sets returns the same
+        answer."""
         import jax.numpy as jnp
         from repro.core.distributed import nks_anchor_topk
         if eligible is not None:
@@ -940,8 +943,9 @@ class NKSEngine:
             if not np.isfinite(float(diams[i])):
                 continue
             ids_i = tuple(sorted(set(int(x) for x in cids[i])))
-            cands.append(Candidate(ids=ids_i, diameter=float(diams[i])))
-        return cands
+            cands.append(Candidate(ids=ids_i, diameter=brute_force.set_diameter(
+                ids_i, self.dataset)))
+        return sorted(cands, key=lambda c: (c.diameter, c.ids))
 
     def _resolve_filter(self, filter) -> "Filter | None":
         return Filter.coerce(filter)

@@ -50,6 +50,7 @@ import threading
 import time
 from collections import deque
 
+from repro.core.backend import DistanceBackend
 from repro.serve.engine import NKSEngine
 from repro.serve.faults import NO_FAULTS, FaultPlan, InjectedCrash, InjectedFault
 
@@ -72,7 +73,10 @@ class RuntimeConfig:
     degrade_watermark: float = 0.75  # queue fraction past which exact sheds
     tier: str = "approx"            # default tier for requests without one
     k: int = 1                      # default top-k
-    backend: str = "numpy"          # distance backend for coalesced batches
+    # Distance backend for coalesced batches: "numpy", "pallas", or a
+    # DistanceBackend instance. Resolved once per runtime, so the pallas
+    # backend's tile/distance caches and its counters span every batch.
+    backend: str | DistanceBackend = "numpy"
 
 
 @dataclasses.dataclass
@@ -187,6 +191,7 @@ class ServingRuntime:
         self.cfg = config or RuntimeConfig()
         self.faults = faults or getattr(engine, "_faults", None) or NO_FAULTS
         self.stats = RuntimeStats()
+        self.backend = engine._resolve_backend(self.cfg.backend)
         self._queue: deque[Ticket] = deque()
         self._deferred: list[Ticket] = []   # ingest parked during a rebuild
         self._lock = threading.Lock()           # guards queue + flags
@@ -441,7 +446,7 @@ class ServingRuntime:
                 with self._engine_lock:
                     results = self.engine.query_batch(
                         queries, k=k, tier=eff_tier,
-                        backend=self.cfg.backend, filter=flt,
+                        backend=self.backend, filter=flt,
                         semantics=sem)
                 break
             except _RETRYABLE as e:

@@ -15,7 +15,6 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.distributed import (distributed_nks_topk, nks_anchor_topk,
@@ -66,8 +65,8 @@ def test_compressed_psum():
         red, _ = compressed_psum({"g": g}, buf, "pod")
         return red["g"]
 
-    fn = shard_map(body, mesh=mesh, in_specs=(P("pod", None),),
-                   out_specs=P("pod", None), check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P("pod", None),),
+                       out_specs=P("pod", None), check_vma=False)
     with mesh:
         out = fn(g_all)
     true_mean = np.asarray(g_all).mean(axis=0)
@@ -98,9 +97,9 @@ def test_pipeline_forward():
                                axis_name="pod")
         return out[None]                      # add the stage axis for out_specs
 
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P("pod", None, None), P(None, None)),
-                   out_specs=P("pod", None, None), check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P("pod", None, None), P(None, None)),
+                       out_specs=P("pod", None, None), check_vma=False)
     with mesh:
         out = fn(w_all, x)                    # (8, M, dim) per stage
     got = np.asarray(out)[-1]                 # last stage's outputs

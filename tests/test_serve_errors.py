@@ -3,6 +3,8 @@ and the stream survives — in both the synchronous loop
 (``handle_request_safe``) and the async runtime path
 (``serve_with_runtime``). Covers the satellite checklist: malformed line,
 unknown op, insert with mismatched attrs schema, tenant-unknown keyword."""
+import json
+
 import numpy as np
 import pytest
 
@@ -113,3 +115,30 @@ def test_sync_and_runtime_answers_agree(engine):
         assert s == a
     assert asynchronous[-1]["op"] == "health"
     assert asynchronous[-1]["generation"] == sync[-1]["generation"]
+
+
+def _launch(monkeypatch, capsys, *argv):
+    from repro.launch import serve as launcher
+    monkeypatch.setattr(launcher, "use_compile_cache", lambda: None)
+    monkeypatch.setattr("sys.argv", ["serve", *argv])
+    launcher.main()
+    out = [json.loads(line) for line in capsys.readouterr().out.splitlines()]
+    return [{k: v for k, v in o.items() if k != "latency_ms"} for o in out]
+
+
+def test_launcher_backend_pallas_answers_like_numpy(monkeypatch, capsys):
+    """``--runtime --backend pallas`` serves the exact tier through the join
+    kernels (the XLA lowering off-TPU) with the numpy route's answers."""
+    argv = ("--runtime", "--corpus", "uniform", "--n", "600", "--d", "4",
+            "--u", "12", "--t", "2", "--tier", "exact", "--k", "2",
+            "--queries", "5")
+    numpy_out = _launch(monkeypatch, capsys, *argv, "--backend", "numpy")
+    pallas_out = _launch(monkeypatch, capsys, *argv, "--backend", "pallas")
+    assert len(numpy_out) == 5 and all(o["results"] for o in numpy_out)
+    assert pallas_out == numpy_out
+
+
+def test_launcher_backend_needs_runtime(monkeypatch, capsys):
+    with pytest.raises(SystemExit) as exc:
+        _launch(monkeypatch, capsys, "--backend", "pallas")
+    assert exc.value.code == 2
