@@ -45,6 +45,7 @@ import numpy as np
 
 from repro.core.subset_search import (_sq_dists_f64, pack_join_mask,
                                       pairwise_l2_numpy)
+from repro.utils.timing import span
 
 _EPS32 = float(np.finfo(np.float32).eps)
 _F32_MAX = float(np.finfo(np.float32).max)
@@ -370,30 +371,32 @@ class NumpyBackend(DistanceBackend):
                          generation: int | None = None,
                          eligible: np.ndarray | None = None
                          ) -> list[DistanceBlock]:
-        t0 = time.perf_counter()
         out = []
-        for ids, r in zip(id_lists, radii):
-            pts = points[ids]
-            self._note_cold_read(points, len(ids))
-            dist = self.pairwise(pts, pts)
-            n_elig = None
-            if eligible is None:
-                count = int((dist <= r).sum()) if np.isfinite(r) else dist.size
-            else:
-                # Mirror the device fold: counts cover eligible pairs only,
-                # so the empty-join signal fires at the filtered selectivity.
-                el = eligible[ids]
-                n_elig = int(el.sum())
-                pair_ok = el[:, None] & el[None, :]
-                count = int(((dist <= r) & pair_ok).sum()) \
-                    if np.isfinite(r) else int(pair_ok.sum())
-            self.stats.subsets += 1
-            self.stats.points_packed += len(ids)
-            self.stats.join_pairs += count
-            out.append(DistanceBlock(n=len(ids), dist=dist, slack=0.0,
-                                     rescore=False, join_count=count,
-                                     n_eligible=n_elig))
-        self.stats.t_dispatch_s += time.perf_counter() - t0
+        with span("nks.backend.dispatch", self.stats, "t_dispatch_s",
+                  subsets=len(id_lists)):
+            for ids, r in zip(id_lists, radii):
+                pts = points[ids]
+                self._note_cold_read(points, len(ids))
+                dist = self.pairwise(pts, pts)
+                n_elig = None
+                if eligible is None:
+                    count = int((dist <= r).sum()) if np.isfinite(r) \
+                        else dist.size
+                else:
+                    # Mirror the device fold: counts cover eligible pairs
+                    # only, so the empty-join signal fires at the filtered
+                    # selectivity.
+                    el = eligible[ids]
+                    n_elig = int(el.sum())
+                    pair_ok = el[:, None] & el[None, :]
+                    count = int(((dist <= r) & pair_ok).sum()) \
+                        if np.isfinite(r) else int(pair_ok.sum())
+                self.stats.subsets += 1
+                self.stats.points_packed += len(ids)
+                self.stats.join_pairs += count
+                out.append(DistanceBlock(n=len(ids), dist=dist, slack=0.0,
+                                         rescore=False, join_count=count,
+                                         n_eligible=n_elig))
         return out
 
 
@@ -783,41 +786,40 @@ class PallasBackend(DistanceBackend):
         threshold count per call. This is the host route's analogue of the
         device tile cache, and what makes auto routing faster than a pure
         :class:`NumpyBackend` pass at the same results."""
-        t0 = time.perf_counter()
         if keys is None:
             keys = [None] * len(id_lists)
         out = []
-        for ids, r, key in zip(id_lists, radii, keys):
-            ck = None if key is None else ("hostdist", key)
-            dist = self._cache_get(ck) if ck is not None else None
-            if dist is None:
-                pts = points[ids]
-                self._note_cold_read(points, len(ids))
-                dist = np.sqrt(_sq_dists_f64(np.asarray(pts, np.float64)))
-                if ck is not None:
-                    self.stats.cache_misses += 1
-                    self._cache_put(ck, dist, dist.nbytes)
-            else:
-                self.stats.cache_hits += 1
-            n_elig = None
-            if eligible is None:
-                count = int((dist <= r).sum())
-            else:
-                el = eligible[ids]
-                n_elig = int(el.sum())
-                count = int(((dist <= r) & el[:, None] & el[None, :]).sum())
-            self.stats.subsets += 1
-            self.stats.points_packed += len(ids)
-            self.stats.join_pairs += count
-            out.append(DistanceBlock(n=len(ids), dist=dist, slack=0.0,
-                                     rescore=False, join_count=count,
-                                     n_eligible=n_elig))
-        dt = time.perf_counter() - t0
+        with span("nks.backend.host", self.stats, ("t_host_s", "t_dispatch_s"),
+                  subsets=len(id_lists)):
+            for ids, r, key in zip(id_lists, radii, keys):
+                ck = None if key is None else ("hostdist", key)
+                dist = self._cache_get(ck) if ck is not None else None
+                if dist is None:
+                    pts = points[ids]
+                    self._note_cold_read(points, len(ids))
+                    dist = np.sqrt(_sq_dists_f64(np.asarray(pts, np.float64)))
+                    if ck is not None:
+                        self.stats.cache_misses += 1
+                        self._cache_put(ck, dist, dist.nbytes)
+                else:
+                    self.stats.cache_hits += 1
+                n_elig = None
+                if eligible is None:
+                    count = int((dist <= r).sum())
+                else:
+                    el = eligible[ids]
+                    n_elig = int(el.sum())
+                    count = int(((dist <= r) & el[:, None]
+                                 & el[None, :]).sum())
+                self.stats.subsets += 1
+                self.stats.points_packed += len(ids)
+                self.stats.join_pairs += count
+                out.append(DistanceBlock(n=len(ids), dist=dist, slack=0.0,
+                                         rescore=False, join_count=count,
+                                         n_eligible=n_elig))
         self.stats.dispatches += 1
         self.stats.host_routed_dispatches += 1
         self.stats.host_routed_subsets += len(id_lists)
-        self.stats.t_host_s += dt
-        self.stats.t_dispatch_s += dt
         return out
 
     def _dispatch(self, points: np.ndarray, id_lists: Sequence[np.ndarray],
@@ -828,136 +830,142 @@ class PallasBackend(DistanceBackend):
         from repro.kernels import ops
         import jax.numpy as jnp
 
-        t0 = time.perf_counter()
-        n_subsets = len(id_lists)
-        # Eligible-dense packing: tiles hold only the eligible rows; the
-        # block carries the packed row map. The pack is filter-dependent, so
-        # both the subset-row cache and the tile cache are bypassed.
-        if elig_dense:
-            row_lists = [np.flatnonzero(eligible[ids]) for ids in id_lists]
-            lengths = np.fromiter((len(rw) for rw in row_lists), np.int32,
-                                  count=n_subsets)
-        else:
-            row_lists = None
-            lengths = np.fromiter((len(ids) for ids in id_lists), np.int32,
-                                  count=n_subsets)
-        # Route over the device plane when the bin packs at least one subset
-        # per shard; thinner bins (the remainder after chunking) stay on a
-        # single device — sharding them would only ship empty slabs.
-        plane = self.plane
-        sharded = plane is not None and n_subsets >= plane.n_shards
-        s_pad = self._round(n_subsets)
-        if sharded:
-            s_pad = plane.shard_pad(s_pad)
-        budget_cells = max(1, self.max_block_bytes // 4)
-        if s_pad * p_pad * p_pad > budget_cells:
-            # Shape-reuse rounding must not blow the budget. Sharding needs a
-            # shard multiple; if even the minimal one is over budget, the bin
-            # drops to the single-device route at its exact size.
-            s_pad = plane.shard_pad(n_subsets) if sharded else n_subsets
-            if sharded and s_pad * p_pad * p_pad > budget_cells:
-                sharded = False
-                s_pad = n_subsets
-
-        lens_pad = np.zeros(s_pad, np.int32)
-        lens_pad[:n_subsets] = lengths
-        # Shard placement: deal subsets to tile slots in snake order of
-        # packed size so each shard's contiguous slab carries level work
-        # (``device_plane.balance_order``). The permutation is a pure
-        # function of the packed lengths — radius-independent, so cached
-        # tiles (which are reused across radii) stay valid — and slot->shard
-        # is what ``shard_cells`` reports, so ``shard_utilisation`` reads the
-        # levelled layout directly. ``inv[i]`` is subset i's tile slot.
-        inv = None
-        if sharded and self.placement == "sorted":
-            from repro.core.device_plane import balance_order
-            perm = balance_order(lens_pad, plane.n_shards)
-            inv = np.empty(s_pad, np.int64)
-            inv[perm] = np.arange(s_pad)
-
-        def slot(i: int) -> int:
-            return i if inv is None else int(inv[i])
-
-        def to_slots(arr):
-            if inv is None:
-                return arr
-            out = np.zeros_like(arr)
-            out[inv] = arr
-            return out
-
-        lens_ship = to_slots(lens_pad)
-        tile_key = None
-        if not elig_dense and not any(k is None for k in keys):
-            tile_key = ("tile", tuple(keys), s_pad, p_pad, sharded,
-                        self.placement if sharded else "none")
-        cached_tile = self._cache_get(tile_key) if tile_key else None
-        if cached_tile is not None:
-            # Packed tiles already live on the device: skip gather, packing,
-            # and H2D entirely; only the radii change between calls. Slacks
-            # ride in the payload, so the hit path touches no per-subset
-            # state at all. Hit/miss counters are per *subset* (a tile hit
-            # serves every subset it packs), so cache_hit_rate reads as the
-            # fraction of subset packs avoided.
-            self.stats.cache_hits += n_subsets
-            x_dev, lens_dev, slacks = cached_tile
-            # Keep the per-subset row entries warm too: a long streak of
-            # tile hits must not LRU-starve them, or a later re-binning
-            # (chunk boundaries shift when radii tighten) re-packs rows the
-            # cache nominally still held. Recency touch only — the hit
-            # counter above already accounts for these subsets.
-            for key in keys:
-                if ("subset", key) in self._cache:
-                    self._cache.move_to_end(("subset", key))
-        else:
-            slacks = np.zeros(n_subsets, np.float64)
-            d = points.shape[1]
-            x = np.zeros((s_pad, p_pad, d), np.float32)
-            for i, (ids, key) in enumerate(zip(id_lists, keys)):
-                if elig_dense:
-                    rows = np.ascontiguousarray(
-                        points[ids[row_lists[i]]], dtype=np.float32)
-                    self._note_cold_read(points, len(row_lists[i]))
-                    slacks[i] = self._slack(rows)
-                else:
-                    rows, slacks[i] = self._subset_rows(points, ids, key)
-                x[slot(i), : lengths[i]] = rows
-            if sharded:
-                # Commit the tile scattered over the mesh's data axis so the
-                # sharded dispatch starts from the right placement (a cached
-                # sharded tile stays resident exactly where it will be used).
-                x_dev, lens_dev = plane.put_sharded(x, lens_ship)
+        with span("nks.backend.pack", self.stats, "t_pack_s",
+                  subsets=len(id_lists), p=p_pad):
+            n_subsets = len(id_lists)
+            # Eligible-dense packing: tiles hold only the eligible rows; the
+            # block carries the packed row map. The pack is filter-dependent,
+            # so both the subset-row cache and the tile cache are bypassed.
+            if elig_dense:
+                row_lists = [np.flatnonzero(eligible[ids]) for ids in id_lists]
+                lengths = np.fromiter((len(rw) for rw in row_lists), np.int32,
+                                      count=n_subsets)
             else:
-                x_dev = jnp.asarray(x)
-                lens_dev = jnp.asarray(lens_ship)
-            if tile_key is not None:
-                self._cache_put(tile_key, (x_dev, lens_dev, slacks),
-                                x.nbytes + slacks.nbytes)
+                row_lists = None
+                lengths = np.fromiter((len(ids) for ids in id_lists), np.int32,
+                                      count=n_subsets)
+            # Route over the device plane when the bin packs at least one
+            # subset per shard; thinner bins (the remainder after chunking)
+            # stay on a single device — sharding them would only ship empty
+            # slabs.
+            plane = self.plane
+            sharded = plane is not None and n_subsets >= plane.n_shards
+            s_pad = self._round(n_subsets)
+            if sharded:
+                s_pad = plane.shard_pad(s_pad)
+            budget_cells = max(1, self.max_block_bytes // 4)
+            if s_pad * p_pad * p_pad > budget_cells:
+                # Shape-reuse rounding must not blow the budget. Sharding needs
+                # a shard multiple; if even the minimal one is over budget, the
+                # bin drops to the single-device route at its exact size.
+                s_pad = plane.shard_pad(n_subsets) if sharded else n_subsets
+                if sharded and s_pad * p_pad * p_pad > budget_cells:
+                    sharded = False
+                    s_pad = n_subsets
 
-        # Pruning radius r + slack, rounded *up* to fp32 so the device
-        # comparison can never be tighter than the published slack contract.
-        # ``r_orig`` is indexed by subset, the shipped vectors by tile slot.
-        r_orig = np.zeros(s_pad, np.float32)
-        r_mask = np.asarray(radii, np.float64) + slacks
-        with np.errstate(over="ignore"):    # nextafter(f32max) saturates to inf
-            r_orig[:n_subsets] = np.nextafter(r_mask.astype(np.float32),
-                                              np.float32(np.inf))
-        r_orig[:n_subsets][~np.isfinite(r_mask)] = np.float32(np.inf)
-        r = to_slots(r_orig)
-        # Filtered dispatch (fold mode): pack each subset's eligibility bits
-        # into the mask word layout. These words are the *only* extra traffic
-        # a filter adds — the tile (cached or not) is filter-independent, and
-        # the readback stays the same packed mask. Eligible-dense tiles skip
-        # the fold (every packed row is eligible by construction).
-        elig_words = el_counts = None
-        if eligible is not None and not elig_dense:
-            el = np.zeros((s_pad, p_pad), dtype=bool)
-            el_counts = np.zeros(n_subsets, np.int64)
-            for i, ids in enumerate(id_lists):
-                eli = eligible[ids]
-                el[slot(i), : len(ids)] = eli
-                el_counts[i] = int(eli.sum())
-            elig_words = pack_join_mask(el)        # (s_pad, ceil(p_pad/32))
-        self.stats.t_pack_s += time.perf_counter() - t0
+            lens_pad = np.zeros(s_pad, np.int32)
+            lens_pad[:n_subsets] = lengths
+            # Shard placement: deal subsets to tile slots in snake order of
+            # packed size so each shard's contiguous slab carries level work
+            # (``device_plane.balance_order``). The permutation is a pure
+            # function of the packed lengths — radius-independent, so cached
+            # tiles (which are reused across radii) stay valid — and
+            # slot->shard is what ``shard_cells`` reports, so
+            # ``shard_utilisation`` reads the levelled layout directly.
+            # ``inv[i]`` is subset i's tile slot.
+            inv = None
+            if sharded and self.placement == "sorted":
+                from repro.core.device_plane import balance_order
+                perm = balance_order(lens_pad, plane.n_shards)
+                inv = np.empty(s_pad, np.int64)
+                inv[perm] = np.arange(s_pad)
+
+            def slot(i: int) -> int:
+                return i if inv is None else int(inv[i])
+
+            def to_slots(arr):
+                if inv is None:
+                    return arr
+                out = np.zeros_like(arr)
+                out[inv] = arr
+                return out
+
+            lens_ship = to_slots(lens_pad)
+            tile_key = None
+            if not elig_dense and not any(k is None for k in keys):
+                tile_key = ("tile", tuple(keys), s_pad, p_pad, sharded,
+                            self.placement if sharded else "none")
+            cached_tile = self._cache_get(tile_key) if tile_key else None
+            if cached_tile is not None:
+                # Packed tiles already live on the device: skip gather,
+                # packing, and H2D entirely; only the radii change between
+                # calls. Slacks ride in the payload, so the hit path touches no
+                # per-subset state at all. Hit/miss counters are per *subset*
+                # (a tile hit serves every subset it packs), so cache_hit_rate
+                # reads as the fraction of subset packs avoided.
+                self.stats.cache_hits += n_subsets
+                x_dev, lens_dev, slacks = cached_tile
+                # Keep the per-subset row entries warm too: a long streak of
+                # tile hits must not LRU-starve them, or a later re-binning
+                # (chunk boundaries shift when radii tighten) re-packs rows the
+                # cache nominally still held. Recency touch only — the hit
+                # counter above already accounts for these subsets.
+                for key in keys:
+                    if ("subset", key) in self._cache:
+                        self._cache.move_to_end(("subset", key))
+            else:
+                slacks = np.zeros(n_subsets, np.float64)
+                d = points.shape[1]
+                x = np.zeros((s_pad, p_pad, d), np.float32)
+                for i, (ids, key) in enumerate(zip(id_lists, keys)):
+                    if elig_dense:
+                        rows = np.ascontiguousarray(
+                            points[ids[row_lists[i]]], dtype=np.float32)
+                        self._note_cold_read(points, len(row_lists[i]))
+                        slacks[i] = self._slack(rows)
+                    else:
+                        rows, slacks[i] = self._subset_rows(points, ids, key)
+                    x[slot(i), : lengths[i]] = rows
+                if sharded:
+                    # Commit the tile scattered over the mesh's data axis so
+                    # the sharded dispatch starts from the right placement (a
+                    # cached sharded tile stays resident exactly where it will
+                    # be used).
+                    x_dev, lens_dev = plane.put_sharded(x, lens_ship)
+                else:
+                    x_dev = jnp.asarray(x)
+                    lens_dev = jnp.asarray(lens_ship)
+                if tile_key is not None:
+                    self._cache_put(tile_key, (x_dev, lens_dev, slacks),
+                                    x.nbytes + slacks.nbytes)
+
+            # Pruning radius r + slack, rounded *up* to fp32 so the device
+            # comparison can never be tighter than the published slack
+            # contract. ``r_orig`` is indexed by subset, the shipped vectors by
+            # tile slot.
+            r_orig = np.zeros(s_pad, np.float32)
+            r_mask = np.asarray(radii, np.float64) + slacks
+            # nextafter(f32max) saturates to inf
+            with np.errstate(over="ignore"):
+                r_orig[:n_subsets] = np.nextafter(r_mask.astype(np.float32),
+                                                  np.float32(np.inf))
+            r_orig[:n_subsets][~np.isfinite(r_mask)] = np.float32(np.inf)
+            r = to_slots(r_orig)
+            # Filtered dispatch (fold mode): pack each subset's eligibility
+            # bits into the mask word layout. These words are the *only* extra
+            # traffic a filter adds — the tile (cached or not) is
+            # filter-independent, and the readback stays the same packed mask.
+            # Eligible-dense tiles skip the fold (every packed row is eligible
+            # by construction).
+            elig_words = el_counts = None
+            if eligible is not None and not elig_dense:
+                el = np.zeros((s_pad, p_pad), dtype=bool)
+                el_counts = np.zeros(n_subsets, np.int64)
+                for i, ids in enumerate(id_lists):
+                    eli = eligible[ids]
+                    el[slot(i), : len(ids)] = eli
+                    el_counts[i] = int(eli.sum())
+                elig_words = pack_join_mask(el)    # (s_pad, ceil(p_pad/32))
         self.stats.h2d_bytes += r.nbytes + \
             (elig_words.nbytes if elig_words is not None else 0) + \
             (0 if cached_tile is not None
@@ -992,19 +1000,19 @@ class PallasBackend(DistanceBackend):
                 rc_orig[:n_subsets] = np.nextafter(
                     r_c.astype(np.float32), np.float32(np.inf))
             rc = to_slots(rc_orig)
-            t_p = time.perf_counter()
-            if sharded:
-                cnt_c = plane.join_batched_counts(
-                    x_dev, lens_dev, rc, elig_words, dtype=self.prune_dtype,
-                    bm=self.bm, bn=self.bn, interpret=self.interpret)
-            else:
-                cnt_c = ops.pairwise_l2_join_batched_counts(
-                    x_dev, lens_dev, rc, elig_words, dtype=self.prune_dtype,
-                    bm=self.bm, bn=self.bn, interpret=self.interpret)
-            counts_c = np.asarray(cnt_c)
-            dtp = time.perf_counter() - t_p
-            self.stats.t_prune_s += dtp
-            self.stats.t_dispatch_s += dtp
+            with span("nks.backend.prune", self.stats,
+                      ("t_prune_s", "t_dispatch_s"), s=s_pad, p=p_pad):
+                if sharded:
+                    cnt_c = plane.join_batched_counts(
+                        x_dev, lens_dev, rc, elig_words,
+                        dtype=self.prune_dtype, bm=self.bm, bn=self.bn,
+                        interpret=self.interpret)
+                else:
+                    cnt_c = ops.pairwise_l2_join_batched_counts(
+                        x_dev, lens_dev, rc, elig_words,
+                        dtype=self.prune_dtype, bm=self.bm, bn=self.bn,
+                        interpret=self.interpret)
+                counts_c = np.asarray(cnt_c)
             self.stats.prune_tier_dispatches += 1
             self.stats.h2d_bytes += rc.nbytes
             self.stats.d2h_bytes += counts_c.nbytes
@@ -1017,55 +1025,54 @@ class PallasBackend(DistanceBackend):
         mask = counts = None
         sub_slots = None
         if pruned is None or not pruned.all():
-            t1 = time.perf_counter()
-            if pruned is not None and pruned.any():
-                # Survivor sub-dispatch: gather surviving slots out of the
-                # committed tile on device (no re-pack, no H2D of rows).
-                surv = np.flatnonzero(~pruned)
-                slots_surv = surv if inv is None else inv[surv]
-                n_surv = len(surv)
-                s_sub = self._round(n_surv)
-                sub_sharded = sharded and n_surv >= plane.n_shards
-                if sub_sharded:
-                    s_sub = plane.shard_pad(s_sub)
-                idx_pad = np.zeros(s_sub, np.int64)
-                idx_pad[:n_surv] = slots_surv
-                lens_sub = np.zeros(s_sub, np.int32)
-                lens_sub[:n_surv] = lengths[surv]
-                r_sub = np.zeros(s_sub, np.float32)
-                r_sub[:n_surv] = r_orig[surv]
-                elig_sub = None
-                if elig_words is not None:
-                    elig_sub = np.zeros((s_sub, elig_words.shape[1]),
-                                        np.uint32)
-                    elig_sub[:n_surv] = elig_words[slots_surv]
-                x_sub = jnp.take(x_dev, jnp.asarray(idx_pad), axis=0)
-                if sub_sharded:
-                    m, c = plane.join_batched_masked(
-                        x_sub, lens_sub, r_sub, elig_sub, bm=self.bm,
-                        bn=self.bn, interpret=self.interpret)
+            fields = ("t_dispatch_s", "t_collective_s") if sharded \
+                else "t_dispatch_s"
+            with span("nks.backend.dispatch", self.stats, fields,
+                      s=s_pad, p=p_pad):
+                if pruned is not None and pruned.any():
+                    # Survivor sub-dispatch: gather surviving slots out of the
+                    # committed tile on device (no re-pack, no H2D of rows).
+                    surv = np.flatnonzero(~pruned)
+                    slots_surv = surv if inv is None else inv[surv]
+                    n_surv = len(surv)
+                    s_sub = self._round(n_surv)
+                    sub_sharded = sharded and n_surv >= plane.n_shards
+                    if sub_sharded:
+                        s_sub = plane.shard_pad(s_sub)
+                    idx_pad = np.zeros(s_sub, np.int64)
+                    idx_pad[:n_surv] = slots_surv
+                    lens_sub = np.zeros(s_sub, np.int32)
+                    lens_sub[:n_surv] = lengths[surv]
+                    r_sub = np.zeros(s_sub, np.float32)
+                    r_sub[:n_surv] = r_orig[surv]
+                    elig_sub = None
+                    if elig_words is not None:
+                        elig_sub = np.zeros((s_sub, elig_words.shape[1]),
+                                            np.uint32)
+                        elig_sub[:n_surv] = elig_words[slots_surv]
+                    x_sub = jnp.take(x_dev, jnp.asarray(idx_pad), axis=0)
+                    if sub_sharded:
+                        m, c = plane.join_batched_masked(
+                            x_sub, lens_sub, r_sub, elig_sub, bm=self.bm,
+                            bn=self.bn, interpret=self.interpret)
+                    else:
+                        m, c = ops.pairwise_l2_join_batched_masked(
+                            x_sub, lens_sub, r_sub, elig_sub, bm=self.bm,
+                            bn=self.bn, interpret=self.interpret)
+                    sub_slots = {int(i): j for j, i in enumerate(surv)}
                 else:
-                    m, c = ops.pairwise_l2_join_batched_masked(
-                        x_sub, lens_sub, r_sub, elig_sub, bm=self.bm,
-                        bn=self.bn, interpret=self.interpret)
-                sub_slots = {int(i): j for j, i in enumerate(surv)}
-            else:
-                if sharded:
-                    m, c = plane.join_batched_masked(
-                        x_dev, lens_dev, r, elig_words, bm=self.bm,
-                        bn=self.bn, interpret=self.interpret)
-                else:
-                    m, c = ops.pairwise_l2_join_batched_masked(
-                        x_dev, lens_dev, r, elig_words, bm=self.bm,
-                        bn=self.bn, interpret=self.interpret)
-            mask = np.asarray(m)
-            counts = np.asarray(c)
-            dt = time.perf_counter() - t1
+                    if sharded:
+                        m, c = plane.join_batched_masked(
+                            x_dev, lens_dev, r, elig_words, bm=self.bm,
+                            bn=self.bn, interpret=self.interpret)
+                    else:
+                        m, c = ops.pairwise_l2_join_batched_masked(
+                            x_dev, lens_dev, r, elig_words, bm=self.bm,
+                            bn=self.bn, interpret=self.interpret)
+                mask = np.asarray(m)
+                counts = np.asarray(c)
             self.stats.join_dispatches += 1
-            self.stats.t_dispatch_s += dt
             self.stats.d2h_bytes += mask.nbytes + counts.nbytes
-            if sharded:
-                self.stats.t_collective_s += dt
 
         self.stats.dispatches += 1
         self.stats.subsets += n_subsets

@@ -69,6 +69,7 @@ from repro.core.types import (Candidate, KeywordDataset, StreamingCorpus,
                               TopK, make_dataset)
 from repro.serve import wal as walmod
 from repro.serve.faults import NO_FAULTS, FaultPlan
+from repro.utils.timing import span
 
 # Process-global corpus-generation tokens: every (engine, compaction) pair
 # gets a unique token, so a DistanceBackend shared across engines can never
@@ -112,11 +113,17 @@ class ScaleStats:
 class PipelineStats:
     """End-to-end accounting for one ``query_batch`` call.
 
-    The four phase timers split the batch wall time the way the ISSUE-2 perf
-    work carves the pipeline: ``plan`` (bucket selection + keyword grouping),
+    The phase timers split the batch wall time by pipeline stage:
+    ``plan`` (bucket selection + keyword grouping),
     ``pack`` (host gather/tile packing, backend-side), ``dispatch`` (device
     dispatch + D2H readback), ``enumerate`` (host Alg. 4 over the join
-    masks). Cache counters mirror the backend's packed-subset LRU.
+    masks). On the device tier ``dispatch`` is the transfer in and the
+    program call only: the blocking copy back of the k sets is
+    ``readback`` (``t_readback_s``), and their float64 rescoring, ranking
+    and id mapping is ``rescore`` (``t_rescore_s``). Each timer is a
+    ``nks.*`` span (``repro.utils.timing.span``), so the same stages show
+    in a profiler trace. Cache counters mirror the backend's packed-subset
+    LRU.
     """
 
     batch_size: int
@@ -129,6 +136,7 @@ class PipelineStats:
     t_plan_s: float = 0.0
     t_pack_s: float = 0.0
     t_dispatch_s: float = 0.0
+    t_readback_s: float = 0.0
     t_enumerate_s: float = 0.0
     cache_hits: int = 0
     cache_misses: int = 0
@@ -165,11 +173,12 @@ class PipelineStats:
     # device time into the coarse bf16 count pass (``t_prune_s``), the fp32
     # masked join (the remainder of ``t_dispatch_s``), and the host float64
     # settlement of surviving tuples (``t_rescore_s``, measured inside the
-    # enumeration stage). ``cells_pruned`` counts fp32 join cells the coarse
-    # tier proved empty and never dispatched. Cost-model routing lands in
-    # ``host_routed_dispatches`` (bins the crossover model sent to the f64
-    # host loop instead of the device). ``bin_occupancy`` maps each size
-    # class (padded width) to [valid, padded] packed point counts, and
+    # enumeration stage; on the device tier, the span ``nks.engine.rescore``
+    # of the k selected sets). ``cells_pruned`` counts fp32 join cells the
+    # coarse tier proved empty and never dispatched. Cost-model routing lands
+    # in ``host_routed_dispatches`` (bins the crossover model sent to the f64
+    # host loop instead of the device). ``bin_occupancy`` maps each size class
+    # (padded width) to [valid, padded] packed point counts, and
     # ``bin_strategy`` names the binning that produced it.
     prune_tier_dispatches: int = 0
     cells_pruned: int = 0
@@ -215,7 +224,9 @@ class PipelineStats:
             "plan_s": round(self.t_plan_s, 6),
             "pack_s": round(self.t_pack_s, 6),
             "dispatch_s": round(self.t_dispatch_s, 6),
+            "readback_s": round(self.t_readback_s, 6),
             "enumerate_s": round(self.t_enumerate_s, 6),
+            "rescore_s": round(self.t_rescore_s, 6),
             "collective_s": round(self.t_collective_s, 6),
             "cache_hit_rate": round(self.cache_hits / probed, 4) if probed else None,
         }
@@ -903,49 +914,49 @@ class NKSEngine:
         skipped outright. The device's fp32 diameters only select the k
         sets; each is rescored in float64 on the host and the k are ranked
         by that, so every mesh that selects the same sets returns the same
-        answer."""
+        answer. Returns external ids.
+
+        Four spans, none inside another: ``nks.device.pack``,
+        ``nks.device.dispatch`` (transfers in and the program call, tagged
+        with the shape q, R, k that picks the compiled program),
+        ``nks.device.readback`` (every wait on the device and copy back) and
+        ``nks.engine.rescore``. On a plane ``t_collective_s`` covers the
+        dispatch and the readback."""
         import jax.numpy as jnp
+        from repro.core.device_plane import pack_groups
         from repro.core.distributed import nks_anchor_topk
         if eligible is not None:
             if any(not eligible[self.dataset.points_with(v)].any()
                    for v in keywords):
                 return []
-        t0 = time.perf_counter()
-        if self.plane is not None:
-            pg = self.plane.pack_groups(self.dataset, list(keywords),
-                                        eligible=eligible)
-            t1 = time.perf_counter()
-            diams, cids = self.plane.nks_topk(jnp.asarray(pg.groups),
-                                              jnp.asarray(pg.mask),
-                                              jnp.asarray(pg.ids), k)
+        on_plane = self.plane is not None
+        collective = ("t_collective_s",) if on_plane else ()
+        with span("nks.device.pack", stats, "t_pack_s"):
+            pack = self.plane.pack_groups if on_plane else pack_groups
+            groups, mask, ids = pack(self.dataset, list(keywords),
+                                     eligible=eligible)
+        q, r = groups.shape[:2]
+        with span("nks.device.dispatch", stats, ("t_dispatch_s", *collective),
+                  q=q, r=r, k=k):
+            topk = self.plane.nks_topk if on_plane else nks_anchor_topk
+            diams, cids = topk(jnp.asarray(groups), jnp.asarray(mask),
+                               jnp.asarray(ids), k)
+        with span("nks.device.readback", stats, ("t_readback_s", *collective)):
             diams = np.asarray(diams)
-            if stats is not None:
+            sets = [tuple(sorted(set(int(x) for x in cids[i])))
+                    for i in range(k) if np.isfinite(float(diams[i]))]
+        if stats is not None:
+            if on_plane:
                 stats.sharded_dispatches += 1
-                stats.t_collective_s += time.perf_counter() - t1
                 for i in range(self.plane.n_shards):
                     stats.shard_dispatches[i] += 1
-        else:
-            from repro.core.device_plane import pack_groups
-            groups, mask, ids = pack_groups(self.dataset, list(keywords),
-                                            eligible=eligible)
-            t1 = time.perf_counter()
-            diams, cids = nks_anchor_topk(jnp.asarray(groups),
-                                          jnp.asarray(mask),
-                                          jnp.asarray(ids), k)
-            diams = np.asarray(diams)
-            if stats is not None:
+            else:
                 stats.shard_dispatches[0] += 1
-        if stats is not None:
-            stats.t_pack_s += t1 - t0
-            stats.t_dispatch_s += time.perf_counter() - t1
-        cands = []
-        for i in range(k):
-            if not np.isfinite(float(diams[i])):
-                continue
-            ids_i = tuple(sorted(set(int(x) for x in cids[i])))
-            cands.append(Candidate(ids=ids_i, diameter=brute_force.set_diameter(
-                ids_i, self.dataset)))
-        return sorted(cands, key=lambda c: (c.diameter, c.ids))
+        with span("nks.engine.rescore", stats, "t_rescore_s"):
+            cands = [Candidate(ids=s, diameter=brute_force.set_diameter(
+                         s, self.dataset)) for s in sets]
+            return self._externalize(
+                sorted(cands, key=lambda c: (c.diameter, c.ids)))
 
     def _resolve_filter(self, filter) -> "Filter | None":
         return Filter.coerce(filter)
@@ -1004,8 +1015,7 @@ class NKSEngine:
                 eligible = flt.evaluate(self.dataset)
                 if self._view is not None:
                     self._view.mask_tombstones(eligible)
-            cands = self._externalize(
-                self._device_topk(resolved, k, eligible=eligible))
+            cands = self._device_topk(resolved, k, eligible=eligible)
             return QueryResult(list(keywords), cands,
                                time.perf_counter() - t0, tier)
         else:
@@ -1044,14 +1054,13 @@ class NKSEngine:
         dispatch/pack stages are weight-blind (the geometric join is a
         superset of the weighted one), only host settlement consumes it.
         Returns (tasks_searched, dispatches_issued, join_pairs)."""
-        t0 = time.perf_counter()
         prepared = []
-        for t in tasks:
-            gl = local_groups(t.f_ids, queries[t.qidx], self.dataset,
-                              eligible=eligible, ctx=ctx)
-            if gl is not None:
-                prepared.append((t, gl))
-        stats.t_plan_s += time.perf_counter() - t0
+        with span("nks.engine.plan", stats, "t_plan_s"):
+            for t in tasks:
+                gl = local_groups(t.f_ids, queries[t.qidx], self.dataset,
+                                  eligible=eligible, ctx=ctx)
+                if gl is not None:
+                    prepared.append((t, gl))
         if not prepared:
             return 0, 0, 0
         d0 = backend.stats.dispatches
@@ -1074,15 +1083,14 @@ class NKSEngine:
             keys=[t.f_ids.tobytes() for t, _ in prepared],
             generation=self._corpus_token,
             eligible=eligible)
-        t1 = time.perf_counter()
         join_pairs = 0
-        for (t, gl), db in zip(prepared, blocks):
-            join_pairs += db.join_count
-            stats.candidates_explored += enumerate_with_block(
-                t.f_ids, gl, queries[t.qidx], self.dataset, pqs[t.qidx], db,
-                timers=timers,
-                weights=None if weights is None else weights[t.qidx])
-        stats.t_enumerate_s += time.perf_counter() - t1
+        with span("nks.engine.enumerate", stats, "t_enumerate_s"):
+            for (t, gl), db in zip(prepared, blocks):
+                join_pairs += db.join_count
+                stats.candidates_explored += enumerate_with_block(
+                    t.f_ids, gl, queries[t.qidx], self.dataset, pqs[t.qidx],
+                    db, timers=timers,
+                    weights=None if weights is None else weights[t.qidx])
         return len(prepared), backend.stats.dispatches - d0, join_pairs
 
     def _batch_search(self, queries: list[list[int]], k: int, tier: str,
@@ -1135,39 +1143,39 @@ class NKSEngine:
         delta = None
         if self._streaming_dirty():
             delta = self._deltas["e" if exact else "a"]
-        t0 = time.perf_counter()
-        # Filtered batch: evaluate the predicate/tenant mask ONCE here; every
-        # downstream stage (plan pruning, group restriction, device fold)
-        # consumes this same array. Tombstoned points are cleared from the
-        # mask too, so eligibility always implies liveness.
-        eligible = None
-        if flt is not None:
-            eligible = flt.evaluate(self.dataset)
-            if self._view is not None:
-                self._view.mask_tombstones(eligible)
-            stats.eligible_points = int(eligible.sum())
-            live = self.dataset.n - self.tombstone_count
-            stats.filter_selectivity = round(
-                stats.eligible_points / live, 6) if live else 0.0
-        # Zone-map pruning: with per-bucket synopses built (synopsis=True /
-        # a disk store) and a filter in play, the planner can skip buckets
-        # whose zone maps are provably disjoint from the predicate before
-        # their member lists are gathered. Pure accounting win — results are
-        # bit-identical with the pruner on or off.
-        zone = None
-        if flt is not None and eligible is not None \
-                and index.structures[0].synopsis is not None:
-            zp = storemod.ZoneMapPruner(flt, self.dataset)
-            zone = zp if zp.active else None
-        # One BatchPlanContext per batch: keyword masks and covering-bucket
-        # selections are memoized for the batch's lifetime (the corpus is
-        # frozen while the batch runs).
-        pctx = plan.BatchPlanContext(self.dataset)
-        bitsets = [pctx.query_bitset(q) for q in exec_queries]
-        if delta is not None:
-            for bs in bitsets:
-                self._view.mask_tombstones(bs)
-        stats.t_plan_s += time.perf_counter() - t0
+        with span("nks.engine.plan", stats, "t_plan_s"):
+            # Filtered batch: evaluate the predicate/tenant mask ONCE here;
+            # every downstream stage (plan pruning, group restriction, device
+            # fold) consumes this same array. Tombstoned points are cleared
+            # from the mask too, so eligibility always implies liveness.
+            eligible = None
+            if flt is not None:
+                eligible = flt.evaluate(self.dataset)
+                if self._view is not None:
+                    self._view.mask_tombstones(eligible)
+                stats.eligible_points = int(eligible.sum())
+                live = self.dataset.n - self.tombstone_count
+                stats.filter_selectivity = round(
+                    stats.eligible_points / live, 6) if live else 0.0
+            # Zone-map pruning: with per-bucket synopses built
+            # (synopsis=True / a disk store) and a filter in play, the planner
+            # can skip buckets whose zone maps are provably disjoint from the
+            # predicate before their member lists are gathered. Pure
+            # accounting win — results are bit-identical with the pruner on
+            # or off.
+            zone = None
+            if flt is not None and eligible is not None \
+                    and index.structures[0].synopsis is not None:
+                zp = storemod.ZoneMapPruner(flt, self.dataset)
+                zone = zp if zp.active else None
+            # One BatchPlanContext per batch: keyword masks and
+            # covering-bucket selections are memoized for the batch's
+            # lifetime (the corpus is frozen while the batch runs).
+            pctx = plan.BatchPlanContext(self.dataset)
+            bitsets = [pctx.query_bitset(q) for q in exec_queries]
+            if delta is not None:
+                for bs in bitsets:
+                    self._view.mask_tombstones(bs)
         explored = {i: set() for i in range(len(exec_queries))} if exact \
             else None
         active = list(range(len(exec_queries)))
@@ -1178,11 +1186,10 @@ class NKSEngine:
                 break
             sstats = ScaleStats(scale=s, active_queries=len(active))
             pstats = plan.PlanStats()
-            t0 = time.perf_counter()
-            tasks = plan.plan_scale(index, s, exec_queries, bitsets, active,
-                                    explored, pstats, delta=delta,
-                                    eligible=eligible, ctx=pctx, zone=zone)
-            stats.t_plan_s += time.perf_counter() - t0
+            with span("nks.engine.plan", stats, "t_plan_s", scale=s):
+                tasks = plan.plan_scale(index, s, exec_queries, bitsets,
+                                        active, explored, pstats, delta=delta,
+                                        eligible=eligible, ctx=pctx, zone=zone)
             sstats.buckets_selected = pstats.buckets_selected
             sstats.duplicate_subsets = pstats.duplicate_subsets
             sstats.filtered_subsets = pstats.filtered_subsets
@@ -1297,7 +1304,17 @@ class NKSEngine:
         (full coverage, unit weights, no scoring) are dropped before
         planning, so results stay bit-identical to a plain call; the device
         tier rejects non-trivial semantics.
+
+        The call is the span ``nks.engine.query_batch`` (metadata: tier,
+        number of queries); its stages are spans inside it.
         """
+        with span("nks.engine.query_batch", tier=tier, queries=len(queries)):
+            return self._query_batch(queries, k, tier, backend, filter,
+                                     semantics)
+
+    def _query_batch(self, queries: Sequence[Sequence[int]], k: int,
+                     tier: str, backend: str | DistanceBackend, filter,
+                     semantics) -> list[QueryResult]:
         flt = self._resolve_filter(filter)
         sem = QuerySemantics.coerce(semantics)
         if sem is not None and tier == "device":
@@ -1324,8 +1341,7 @@ class NKSEngine:
                 stats.eligible_points = int(eligible.sum())
             out = []
             for q, rq in zip(queries, resolved):
-                cands = self._externalize(
-                    self._device_topk(rq, k, stats, eligible=eligible))
+                cands = self._device_topk(rq, k, stats, eligible=eligible)
                 # echo the caller's keywords (tenant-local on a namespaced
                 # corpus), never the resolved global slots
                 out.append(QueryResult(list(q), cands, 0.0, tier))

@@ -53,6 +53,7 @@ from collections import deque
 from repro.core.backend import DistanceBackend
 from repro.serve.engine import NKSEngine
 from repro.serve.faults import NO_FAULTS, FaultPlan, InjectedCrash, InjectedFault
+from repro.utils.timing import span
 
 
 class TransientDispatchError(RuntimeError):
@@ -100,6 +101,13 @@ class RuntimeStats:
     bg_compactions: int = 0
     bg_compaction_faults: int = 0
     bg_compaction_errors: int = 0   # unexpected rebuild exceptions survived
+    # Seconds, summed over query tickets, from submit until the worker took
+    # the ticket into a batch (the coalescing wait included): a wait that
+    # starts on one thread and ends on another, so a counter, not a span.
+    t_queue_wait_s: float = 0.0
+    # Seconds the worker spent in the coalescing wait itself
+    # (span ``nks.runtime.batch_window``).
+    t_batch_window_s: float = 0.0
 
     def as_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -350,7 +358,9 @@ class ServingRuntime:
                 if run is not None:
                     self._exec_ingest_run(run)
                 elif batch:
-                    self._exec_query_batch(batch)
+                    with span("nks.runtime.batch", seq=self.stats.batches,
+                              size=len(batch)):
+                        self._exec_query_batch(batch)
                 # else: the batch-window wait inside _gather_locked released
                 # the lock and the compactor flushed deferred ingest to the
                 # queue front — the ingest barrier kept everything, so there
@@ -390,7 +400,9 @@ class ServingRuntime:
                 < self.cfg.batch_window_s:
             # Young head: give near-simultaneous arrivals one window to
             # coalesce before dispatching a tiny batch.
-            self._work.wait(self.cfg.batch_window_s)
+            with span("nks.runtime.batch_window", self.stats,
+                      "t_batch_window_s"):
+                self._work.wait(self.cfg.batch_window_s)
         batch, keep = [], deque()
         pending = list(self._queue)
         for i, t in enumerate(pending):
@@ -406,6 +418,8 @@ class ServingRuntime:
             else:
                 keep.append(t)
         self._queue = keep
+        now = time.monotonic()
+        self.stats.t_queue_wait_s += sum(now - t.submitted_at for t in batch)
         return batch
 
     def _gather_ingest_locked(self) -> list[Ticket]:

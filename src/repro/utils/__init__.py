@@ -1,3 +1,2 @@
-"""Small shared utilities (timing, PRNG helpers, CSR helpers)."""
+"""Small shared utilities (stage spans, CSR helpers)."""
 from repro.utils.csr import CSR, csr_from_lists, invert_csr  # noqa: F401
-from repro.utils.timing import Timer  # noqa: F401
