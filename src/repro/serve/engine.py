@@ -118,8 +118,9 @@ class PipelineStats:
     ``pack`` (host gather/tile packing, backend-side), ``dispatch`` (device
     dispatch + D2H readback), ``enumerate`` (host Alg. 4 over the join
     masks). On the device tier ``dispatch`` is the transfer in and the
-    program call only: the blocking copy back of the k sets is
-    ``readback`` (``t_readback_s``), and their float64 rescoring, ranking
+    program call only: the copy back of the k sets and their diameters,
+    one blocking transfer of both outputs, is ``readback``
+    (``t_readback_s``), and their float64 rescoring, ranking
     and id mapping is ``rescore`` (``t_rescore_s``). Each timer is a
     ``nks.*`` span (``repro.utils.timing.span``), so the same stages show
     in a profiler trace. Cache counters mirror the backend's packed-subset
@@ -919,9 +920,11 @@ class NKSEngine:
         Four spans, none inside another: ``nks.device.pack``,
         ``nks.device.dispatch`` (transfers in and the program call, tagged
         with the shape q, R, k that picks the compiled program),
-        ``nks.device.readback`` (every wait on the device and copy back) and
-        ``nks.engine.rescore``. On a plane ``t_collective_s`` covers the
-        dispatch and the readback."""
+        ``nks.device.readback`` (one ``jax.device_get`` of both outputs,
+        which starts both copies before it waits on either; everything after
+        it is numpy) and ``nks.engine.rescore``. On a plane
+        ``t_collective_s`` covers the dispatch and the readback."""
+        import jax
         import jax.numpy as jnp
         from repro.core.device_plane import pack_groups
         from repro.core.distributed import nks_anchor_topk
@@ -942,9 +945,9 @@ class NKSEngine:
             diams, cids = topk(jnp.asarray(groups), jnp.asarray(mask),
                                jnp.asarray(ids), k)
         with span("nks.device.readback", stats, ("t_readback_s", *collective)):
-            diams = np.asarray(diams)
-            sets = [tuple(sorted(set(int(x) for x in cids[i])))
-                    for i in range(k) if np.isfinite(float(diams[i]))]
+            diams, cids = jax.device_get((diams, cids))
+            sets = [tuple(sorted(set(row.tolist())))
+                    for d, row in zip(diams, cids) if np.isfinite(d)]
         if stats is not None:
             if on_plane:
                 stats.sharded_dispatches += 1
