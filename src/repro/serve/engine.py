@@ -1272,6 +1272,29 @@ class NKSEngine:
                 stats.bin_occupancy[edge] = (dp, dpad)
         return pqs, stats
 
+    def batch_shares_work(self, tier: str, filter=None) -> bool:
+        """Whether queries batched into one ``query_batch`` call on ``tier``
+        under ``filter`` share work, so that a serving runtime gains by
+        waiting for companions before it dispatches a batch.
+
+        * ``exact`` and ``approx``: yes. Planning, Algorithm-2 dedup and the
+          fused join dispatches are amortised over the batch.
+        * ``device`` with a filter: yes. The eligibility mask is evaluated
+          once per batch.
+        * ``device`` without a filter: no. Each query is its own pack,
+          anchor-star program and readback, so a companion saves nothing.
+          A device program that served a whole batch would flip this.
+
+        A filter that does not parse, whatever it raises, counts as sharing
+        work, which keeps the wait: this runs on the runtime's worker
+        thread, and ``query_batch`` reports the error to the request."""
+        if tier != "device":
+            return True
+        try:
+            return self._resolve_filter(filter) is not None
+        except Exception:
+            return True
+
     def query_batch(self, queries: Sequence[Sequence[int]], k: int = 1,
                     tier: str = "approx",
                     backend: str | DistanceBackend = "numpy",

@@ -13,8 +13,11 @@ production shape sitting between a frontend and :class:`NKSEngine`:
   * **coalescing worker** — one thread drives the engine. Queued queries
     with the same (tier, k, filter) are coalesced into a single
     ``query_batch`` call, amortising the plan stage exactly the way the
-    batched pipeline amortises dispatch; a short batch window lets
-    near-simultaneous arrivals merge.
+    batched pipeline amortises dispatch. Where a batch's queries share
+    work (``NKSEngine.batch_shares_work``), a short batch window lets
+    near-simultaneous arrivals merge; a batch that shares none (the
+    unfiltered device tier) is taken at once, counted in
+    ``RuntimeStats.windows_skipped``.
   * **retry with backoff** — a transient dispatch failure retries up to
     ``max_retries`` with exponential backoff; retries are bounded, and a
     batch that keeps failing degrades to per-request execution so one
@@ -67,7 +70,9 @@ _RETRYABLE = (InjectedFault, TransientDispatchError)
 class RuntimeConfig:
     max_queue: int = 256            # admission bound (backpressure past it)
     max_batch: int = 32             # coalesced query batch cap
-    batch_window_s: float = 0.002   # wait this long to let arrivals coalesce
+    # Wait this long to let arrivals coalesce, for batches whose queries
+    # share work; others skip it (RuntimeStats.windows_skipped).
+    batch_window_s: float = 0.002
     default_deadline_s: float | None = None   # None = no deadline
     max_retries: int = 3            # transient dispatch retries per batch
     retry_backoff_s: float = 0.005  # base backoff (doubles per attempt)
@@ -108,6 +113,9 @@ class RuntimeStats:
     # Seconds the worker spent in the coalescing wait itself
     # (span ``nks.runtime.batch_window``).
     t_batch_window_s: float = 0.0
+    # Batches taken without the coalescing window because their queries
+    # share no work (NKSEngine.batch_shares_work): unfiltered device ones.
+    windows_skipped: int = 0
 
     def as_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -394,7 +402,12 @@ class ServingRuntime:
         """Pop a coalescable run of query tickets (same tier/k/filter)."""
         head = self._queue[0]
         key = self._batch_key(head.request)
-        if len(self._queue) < self.cfg.max_batch \
+        if not self.engine.batch_shares_work(key[0],
+                                             head.request.get("filter")):
+            # A companion would save this batch no work: take it now.
+            # Requests that queued meanwhile still coalesce below.
+            self.stats.windows_skipped += 1
+        elif len(self._queue) < self.cfg.max_batch \
                 and self.cfg.batch_window_s > 0 \
                 and time.monotonic() - head.submitted_at \
                 < self.cfg.batch_window_s:

@@ -24,7 +24,8 @@ import time
 import numpy as np
 import pytest
 
-from repro.data.synthetic import random_queries, synthetic_dataset
+from repro.data.synthetic import (attach_attrs, random_queries,
+                                  synthetic_dataset)
 from repro.serve.engine import NKSEngine
 from repro.serve.faults import FaultPlan, InjectedCrash
 from repro.serve.runtime import RuntimeConfig, ServingRuntime
@@ -80,6 +81,116 @@ def test_mixed_keys_still_parity(engine):
     for (q, tier, k), got in zip(specs, results):
         want = engine.query([int(v) for v in q], k=k, tier=tier)
         assert _keys(got.payload["candidates"]) == _keys(want.candidates)
+
+
+# -------------------------------------------------------------- batch window
+PRICED = {"where": [["price", "<", 60.0]]}
+
+
+@pytest.fixture(scope="module")
+def attr_engine():
+    """An engine over a corpus with attribute columns, so device requests
+    can carry a filter."""
+    return NKSEngine(attach_attrs(_corpus(), seed=1), seed=3,
+                     compact_min=10_000)
+
+
+@pytest.mark.parametrize("tier,flt,shares", [
+    ("exact", None, True),
+    ("approx", None, True),
+    ("approx", PRICED, True),
+    ("device", PRICED, True),
+    ("device", None, False),
+    ("device", {}, False),                   # an empty filter is no filter
+    ("device", {"where": [1]}, True),        # malformed: query_batch says so
+    ("device", {"where": [{"a": 1, "b": 2, "c": 3}]}, True),
+])
+def test_batch_shares_work(attr_engine, tier, flt, shares):
+    assert attr_engine.batch_shares_work(tier, flt) is shares
+
+
+@pytest.mark.parametrize("flt", [
+    {"where": [1]},
+    {"where": [{"a": 1, "b": 2, "c": 3}]},   # KeyError inside the parser
+    {"where": [["price", "~", 1.0]]},
+    {"where": "price"},
+], ids=["scalar", "three-key-dict", "unknown-op", "string"])
+def test_malformed_device_filter_is_answered_and_the_worker_lives(
+        attr_engine, flt):
+    """The window decision runs on the worker thread: a filter it cannot
+    parse must reach ``query_batch`` as an ``error`` response, not take the
+    worker down, and the next request is still served."""
+    q = random_queries(attr_engine.dataset, 3, 1, seed=13)[0]
+    want = attr_engine.query(q, k=2, tier="device")
+    with ServingRuntime(attr_engine,
+                        RuntimeConfig(batch_window_s=0.0)) as rt:
+        bad = rt.submit({"op": "query", "keywords": q, "k": 2,
+                         "tier": "device", "filter": flt}).result(30)
+        good = rt.submit({"op": "query", "keywords": q, "k": 2,
+                          "tier": "device"}).result(30)
+        assert not rt.health()["crashed"]
+    assert bad.status == "error" and bad.error
+    assert good.ok
+    assert _keys(good.payload["candidates"]) == _keys(want.candidates)
+
+
+@pytest.mark.parametrize("tier,flt,waits", [
+    ("exact", None, True),
+    ("approx", None, True),
+    ("device", PRICED, True),
+    ("device", None, False),
+])
+def test_lone_request_waits_the_window_only_where_batches_share_work(
+        attr_engine, tier, flt, waits):
+    """The window buys a batch its companions' share of planning, dedup and
+    fused dispatches (exact, approx) or of the eligibility mask (filtered
+    device). An unfiltered device batch runs one program per query, so a
+    lone request is taken at once, and the skip is counted."""
+    q = random_queries(attr_engine.dataset, 3, 1, seed=13)[0]
+    want = attr_engine.query(q, k=2, tier=tier, filter=flt)
+    cfg = RuntimeConfig(batch_window_s=0.05)
+    with ServingRuntime(attr_engine, cfg) as rt:
+        got = rt.submit({"op": "query", "keywords": q, "k": 2, "tier": tier,
+                         "filter": flt}).result(30)
+        assert rt.health()["stats"]["windows_skipped"] == \
+            rt.stats.windows_skipped
+    assert got.ok
+    assert _keys(got.payload["candidates"]) == _keys(want.candidates)
+    st = rt.stats
+    assert st.batches == 1
+    if waits:
+        assert st.t_queue_wait_s >= cfg.batch_window_s
+        assert st.t_batch_window_s > 0.0 and st.windows_skipped == 0
+    else:
+        assert st.t_queue_wait_s < cfg.batch_window_s / 2
+        assert st.t_batch_window_s == 0.0 and st.windows_skipped == 1
+
+
+@pytest.mark.parametrize("flt", [None, PRICED], ids=["plain", "filtered"])
+def test_device_requests_behind_a_busy_worker_coalesce(attr_engine, flt):
+    """Skipping the window takes nothing away from coalescing: device
+    requests that queue while the worker is busy share one batch, and each
+    answer is the direct query's."""
+    queries = random_queries(attr_engine.dataset, 3, 6, seed=17)
+    want = [attr_engine.query(q, k=2, tier="device", filter=flt)
+            for q in queries]
+    with ServingRuntime(attr_engine,
+                        RuntimeConfig(batch_window_s=0.05)) as rt:
+        with rt._engine_lock:                       # stall the worker
+            tickets = [rt.submit({"op": "query", "keywords": q, "k": 2,
+                                  "tier": "device", "filter": flt})
+                       for q in queries]
+        results = [t.result(30) for t in tickets]
+    assert all(r.ok for r in results)
+    for got, w in zip(results, want):
+        assert _keys(got.payload["candidates"]) == _keys(w.candidates)
+    assert rt.stats.batches < len(queries)          # coalescing happened
+    assert rt.stats.batched_queries == len(queries)
+    if flt is None:
+        assert rt.stats.t_batch_window_s == 0.0
+        assert rt.stats.windows_skipped == rt.stats.batches
+    else:
+        assert rt.stats.windows_skipped == 0
 
 
 def test_consecutive_ingest_ops_coalesce_into_one_run(tmp_path):

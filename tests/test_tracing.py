@@ -4,8 +4,9 @@
 same stage into the profiler's trace. These tests hold it to that: the
 seconds land in the named fields, the numpy control plane still imports
 without JAX, the device tier's four stages are all timed and fit inside
-the batch, the runtime counts a request's queue wait, a profiler trace
-of one request through the runtime nests the spans as the code does, and
+the batch, the runtime counts a request's queue wait (and waits the batch
+window only where the batch shares work), a profiler trace of one request
+through the runtime nests the spans as the code does, and
 the exact tier's plan, backend and enumeration stages land in the trace
 inside their batch.
 """
@@ -19,7 +20,8 @@ import time
 
 import pytest
 
-from repro.data.synthetic import random_queries, synthetic_dataset
+from repro.data.synthetic import (attach_attrs, random_queries,
+                                  synthetic_dataset)
 from repro.serve.engine import NKSEngine
 from repro.serve.runtime import RuntimeConfig, ServingRuntime
 from repro.utils.timing import span
@@ -71,7 +73,8 @@ def test_span_and_engine_import_without_jax():
 
 @pytest.fixture(scope="module")
 def device_engine():
-    ds = synthetic_dataset(n=300, d=5, u=24, t=2, seed=0)
+    ds = attach_attrs(synthetic_dataset(n=300, d=5, u=24, t=2, seed=0),
+                      seed=1)
     eng = NKSEngine(ds, seed=3, build_exact=False, build_approx=False)
     queries = random_queries(ds, 3, 6, seed=1)
     eng.query_batch(queries[:1], k=2, tier="device")      # compile once
@@ -93,15 +96,25 @@ def test_device_tier_times_each_stage(device_engine):
     assert ph["rescore_s"] == round(st.t_rescore_s, 6)
 
 
-def test_lone_request_counts_its_queue_wait(device_engine):
+@pytest.mark.parametrize("flt", [{"where": [["price", "<", 60.0]]}, None],
+                         ids=["filtered", "plain"])
+def test_lone_request_counts_its_queue_wait(device_engine, flt):
+    """A filtered device batch shares its eligibility mask, so a lone
+    request waits the window and its queue wait holds it; an unfiltered one
+    shares nothing and is taken without it."""
     eng, queries = device_engine
     cfg = RuntimeConfig(batch_window_s=0.05)
     with ServingRuntime(eng, cfg) as rt:
         res = rt.submit({"op": "query", "keywords": queries[0], "k": 1,
-                         "tier": "device"}).result(30)
+                         "tier": "device", "filter": flt}).result(30)
         assert res.ok
-        assert rt.stats.t_queue_wait_s >= cfg.batch_window_s
-        assert rt.stats.t_batch_window_s > 0.0
+        assert rt.stats.t_queue_wait_s > 0.0
+        if flt is not None:
+            assert rt.stats.t_queue_wait_s >= cfg.batch_window_s
+            assert rt.stats.t_batch_window_s > 0.0
+        else:
+            assert rt.stats.t_queue_wait_s < cfg.batch_window_s
+            assert rt.stats.t_batch_window_s == 0.0
         assert rt.health()["stats"]["t_queue_wait_s"] == \
             rt.stats.t_queue_wait_s
 
@@ -138,7 +151,9 @@ def test_profiler_trace_nests_the_served_path(device_engine, tmp_path):
     [disp] = spans["nks.device.dispatch"]
     [back] = spans["nks.device.readback"]
     [resc] = spans["nks.engine.rescore"]
-    assert "nks.runtime.batch_window" in spans and "nks.device.pack" in spans
+    # an unfiltered device batch shares no work, so it waits no window
+    assert "nks.runtime.batch_window" not in spans
+    assert "nks.device.pack" in spans
     assert batch[0] <= qb[0] and qb[1] <= batch[1]
     assert qb[0] <= disp[0] and disp[1] <= qb[1]
     assert disp[1] <= back[0] <= back[1] <= resc[0] <= resc[1] <= qb[1]
